@@ -1,11 +1,10 @@
 """Admission plane: overload control, priority shedding, TPU failover.
 
 The decision-path guardian between the serving plane (gRPC/HTTP
-handlers) and the storage/TPU plane. Round-5 evidence (4/4 device
-probes hung, DEVICE_PROBES_r05.log) showed the device plane can vanish
-for minutes while the serving path has no concept of an unhealthy
-backend — a stalled ``device_sync`` blocked every batched decision
-behind it. Three cooperating pieces fix that:
+handlers) and the storage/TPU plane. A device call can hang: the
+device plane can vanish for minutes, and a serving path with no concept
+of an unhealthy backend then blocks every batched decision behind one
+stalled ``device_sync``. Three cooperating pieces fix that:
 
 * :mod:`breaker` — a device-plane health monitor + circuit breaker
   (closed/open/half-open) fed by batch outcomes and a stalled-batch

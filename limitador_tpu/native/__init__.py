@@ -608,9 +608,8 @@ class HotStaged:
     """One hot begin's outputs: the response-code column, the staged
     kernel geometry, and the per-kernel-row metadata the finish pass
     needs. ``codes`` / per-row arrays are owned copies (the lane's
-    scratch is reused by the next begin); the staging column views are
-    consumed by the kernel launch before the caller releases the
-    storage lock."""
+    scratch is reused by the next begin), and so are the staging
+    columns the kernel launch takes (``NativeHotLane.kernel_columns``)."""
 
     __slots__ = (
         "codes", "k", "nhits", "H", "rows", "row_nhits", "row_delta",
@@ -932,13 +931,19 @@ class NativeHotLane:
         )
 
     def kernel_columns(self, H: int):
-        """The staged column views for ``begin_check_columnar`` —
-        consumed by the launch while the caller still holds the storage
-        lock (the next begin reuses the buffers)."""
+        """The staged columns for ``begin_check_columnar``, as owned
+        copies. A launch does NOT consume its host arguments before it
+        returns: the CPU backend aliases an aligned numpy buffer
+        outright and an accelerator may read it until the transfer
+        completes, so handing it views of the staging buffers lets the
+        next begin rewrite a batch the device has not read yet (seen as
+        hot keys over-admitted whenever a launch lagged, e.g. behind a
+        compile).
+        ``fresh`` is never written and stays a view."""
         return (
-            self.slots[:H], self.deltas[:H], self.maxes[:H],
-            self.windows[:H], self.req[:H], self.fresh[:H],
-            self.bucket[:H],
+            self.slots[:H].copy(), self.deltas[:H].copy(),
+            self.maxes[:H].copy(), self.windows[:H].copy(),
+            self.req[:H].copy(), self.fresh[:H], self.bucket[:H].copy(),
         )
 
     def finish(self, staged: HotStaged, admitted, hit_ok):

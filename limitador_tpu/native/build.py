@@ -32,6 +32,7 @@ import os
 import shutil
 import subprocess
 import threading
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 __all__ = [
@@ -173,6 +174,9 @@ class NativeLib:
         #: sanitizer variant resolved at first load (TPU_NATIVE_SANITIZE);
         #: None = the plain -O2 build
         self.variant: Optional[str] = None
+        #: wall seconds this process spent compiling the library; None
+        #: when a current build was found on disk
+        self.build_seconds: Optional[float] = None
         _REGISTRY[name] = self
 
     # -- staleness ----------------------------------------------------------
@@ -259,7 +263,9 @@ class NativeLib:
                 self.stamp_path = self.so_path + ".sha256"
             digest = self._digest()
             if self._stale(digest):
+                started = time.monotonic()
                 self._build_error = self._build(digest)
+                self.build_seconds = round(time.monotonic() - started, 3)
                 if self._build_error is not None:
                     return None
             try:
@@ -295,6 +301,7 @@ def build_status() -> dict:
             "attempted": attempted,
             "loaded": lib.loaded,
             "build_error": lib.build_error,
+            "build_seconds": lib.build_seconds,
             "sanitizer": lib.variant,
         }
     return out

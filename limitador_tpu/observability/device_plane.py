@@ -3,9 +3,8 @@
 The serving-plane metrics (metrics.py) mirror the reference's
 prometheus_metrics.rs surface; this module makes the TPU plane —
 micro-batcher queues, device batch phases, shard table occupancy —
-legible without attaching a debugger (BENCH_r05 showed an ~80x gap
-between kernel rate and the served path with nothing in /metrics to
-localize it).
+legible without attaching a debugger: a gap between the kernel's rate
+and the served path needs something in /metrics to localize it.
 
 Three pieces:
 

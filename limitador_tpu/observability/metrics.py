@@ -912,8 +912,7 @@ class PrometheusMetrics:
         self.standby_warm_seconds = Gauge(
             "standby_warm_seconds",
             "Seconds the standby's kernel warm-up took (served from "
-            "the persistent XLA cache on a re-boot when "
-            "--xla-cache-dir is set)",
+            "the persistent XLA compile cache on a re-boot)",
             registry=self.registry,
         )
         # -- flight recorder (observability/flight.py, ISSUE 16): the

@@ -29,20 +29,21 @@ is the Python half:
   over budget divided by the error budget (1 - target quantile); the
   watchdog fires only when BOTH windows burn, so a single slow batch
   can't page and a sustained regression can't hide.
-* :func:`device_backed_runtime` — the PR 6 bench probe at runtime: is
-  a non-CPU jax backend actually serving this process? Exported as the
-  ``device_backed`` gauge and a ``/debug/stats`` field so CPU-fallback
-  deployments are machine-visible outside bench rows.
+* :func:`device_backed_runtime` — is a non-CPU jax backend actually
+  serving this process? Exported as the ``device_backed`` gauge, a
+  ``/debug/stats`` field and the bench rows' ``device_backed`` bit, so
+  a deployment serving from the CPU on purpose is machine-visible.
 """
 
 from __future__ import annotations
 
-import sys
 import threading
 import time
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
+
+from ..device import device_report
 
 __all__ = [
     "PHASES",
@@ -106,19 +107,14 @@ def device_backed_runtime() -> Optional[bool]:
     """Is a non-CPU jax backend actually serving this process? None
     when jax was never imported (memory/disk servers must not pay a jax
     import for a diagnostics bit); cached after the first real answer.
-    The bench-side probe (bench.py ``device_backed``) subprocesses to
-    keep its own process clean — here the process IS the deployment, so
-    asking the already-initialized backend is both cheap and the truth
-    that matters."""
+    The process IS the deployment — only it can hold the chip — so
+    asking its own backend is both cheap and the truth that matters."""
     global _DEVICE_BACKED
     if _DEVICE_BACKED is None:
-        jax = sys.modules.get("jax")
-        if jax is None:
+        report = device_report()
+        if report is None:
             return None
-        try:
-            _DEVICE_BACKED = jax.devices()[0].platform not in ("", "cpu")
-        except Exception:
-            _DEVICE_BACKED = False
+        _DEVICE_BACKED = report["platform"] != "cpu"
     return _DEVICE_BACKED
 
 

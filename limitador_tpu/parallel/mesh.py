@@ -27,10 +27,10 @@ same way; request vectors are replicated.
 Collective-lean variants
 ------------------------
 Collectives only pay for themselves when a batch actually needs them, and
-BENCH_r05 showed the always-coupled launch scaling NEGATIVELY (1.91M/s on
-8 shards vs 2.60M/s on one): every batch paid a psum over the global
-region plus a pmin over the full replicated request vector, whether or
-not any hit was global or any request spanned shards. The host stages
+the always-coupled launch scaled NEGATIVELY with shard count: every batch
+paid a psum over the global region plus a pmin over the full replicated
+request vector, whether or not any hit was global or any request spanned
+shards. The host stages
 per-shard hits and KNOWS both facts, so ``sharded_check_and_update``
 takes two static flags:
 
@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax._src.distributed import global_state as _dist_state
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.kernel import check_and_update_core, update_core
@@ -111,21 +112,11 @@ _NEVER = jnp.iinfo(jnp.int32).max
 
 
 def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across JAX versions: the public API (>= 0.6,
-    ``check_vma``) vs ``jax.experimental.shard_map`` (0.4.x,
-    ``check_rep``). Replication checking is disabled either way — the
+    """``jax.shard_map`` with replication checking off — the
     cross-device pmin/psum coupling below is deliberate."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -193,19 +184,9 @@ def initialize_pod(
     2-process parity harness run a pod on one box. Idempotent: a
     second call in an already-initialized process just returns the
     live topology."""
-    try:
-        from jax._src.distributed import global_state as _dist_state
-    except ImportError:  # pragma: no cover - newer jax layouts
-        _dist_state = getattr(jax.distributed, "global_state", None)
-    if (
-        _dist_state is not None
-        and getattr(_dist_state, "coordinator_address", None) is not None
-    ):
+    if _dist_state.coordinator_address is not None:
         return pod_info()
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass  # older jaxlibs: TPU pods don't need the CPU collectives
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=int(num_processes),
@@ -365,12 +346,7 @@ def pod_barrier(tag: str, timeout_ms: int = 120_000) -> None:
     serve forwarded decisions)."""
     if jax.process_count() <= 1:
         return
-    try:
-        from jax._src.distributed import global_state
-    except ImportError:  # pragma: no cover - newer jax layouts
-        global_state = getattr(jax.distributed, "global_state", None)
-
-    client = getattr(global_state, "client", None)
+    client = _dist_state.client
     if client is None:  # pragma: no cover - non-distributed fallback
         pod_sync(tag)
         return
@@ -870,11 +846,7 @@ class PodPsumLane:
         the `jax.distributed` runtime. Pure control-plane RPC — a
         device-collective exchange would deadlock against concurrent
         local launches (the pod_sync caveat)."""
-        try:
-            from jax._src.distributed import global_state
-        except ImportError:  # pragma: no cover - newer jax layouts
-            global_state = getattr(jax.distributed, "global_state", None)
-        client = getattr(global_state, "client", None)
+        client = _dist_state.client
         if client is None:
             # A multi-host lane without a coordination client must FAIL
             # the round, not fabricate a healthy one: returning

@@ -79,6 +79,7 @@ from aiohttp import web
 
 from ..core.cel import Context
 from ..core.limit import Limit
+from ..device import device_report
 from ..observability.device_plane import (
     JaxProfiler,
     ProfilerStateError,
@@ -140,6 +141,7 @@ DEBUG_SOURCE_SECTIONS = (
 #: endpoint its dashboards and benches scrape.
 DEBUG_STATS_SECTIONS = (
     "profiler",
+    "device",
     "native_build",
     "native_hot_lane",
     "lease",
@@ -649,6 +651,9 @@ class _Api:
         profiler state."""
         stats = collect_debug_stats(*self.debug_sources)
         stats["profiler"] = self.profiler.status()
+        device = device_report()
+        if device is not None:
+            stats["device"] = device
         try:
             from ..native.build import build_status
 

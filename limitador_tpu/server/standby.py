@@ -16,8 +16,9 @@ three BEFORE it is a member, so the join itself (server/resize.py
   are few programs to compile), against a scratch table of the SAME
   capacity the serving storage uses — jit caches key on shapes, so a
   mismatched capacity would compile programs the serving path never
-  reuses. With ``--xla-cache-dir`` the programs also persist to disk,
-  so even the standby's own warm-up is fast after its first boot.
+  reuses. The programs also persist in the compile cache
+  (``device.enable_compile_cache``), so even the standby's own warm-up
+  is fast after its first boot.
 * **state** — the coordinator ships limits + the plan-cache seed over
   the ``join_admin``/``plan_seed`` lane kinds (armed here) before any
   routing changes, and the PR 15 migrate lane moves the joiner's shard

@@ -75,7 +75,7 @@ class InMemoryStorage(CounterStorage):
     def _simple_get_or_create(self, limit: Limit) -> ExpiringValue:
         # NOT setdefault(limit, _new_cell(limit)): that constructed (and
         # discarded) a fresh cell on every call — the single largest
-        # allocation churn of the oracle hot path (BENCH_r05, 85.2k/s).
+        # allocation churn of the oracle hot path.
         ev = self._simple.get(limit)
         if ev is None:
             ev = _new_cell(limit)

@@ -59,7 +59,7 @@ __all__ = [
 ]
 
 DEFAULT_TARGETS = ("limitador_tpu", "tests", "bench.py",
-                   "__graft_entry__.py")
+                   "chip_smoke.py", "__graft_entry__.py")
 
 #: the checked-in baseline/suppression file, repo-relative. Empty at
 #: HEAD (tests/test_analysis.py asserts it): a finding lands here only

@@ -41,7 +41,7 @@ __all__ = [
 ]
 
 DEFAULT_TARGETS = ("limitador_tpu", "tests", "bench.py",
-                   "__graft_entry__.py")
+                   "chip_smoke.py", "__graft_entry__.py")
 
 
 def _legacy(ctx: RepoContext, findings) -> List[str]:

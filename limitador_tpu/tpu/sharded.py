@@ -25,7 +25,7 @@ the storage (BASELINE.json config 5, doc/topologies.md:1-37).
 Scaling discipline (ISSUE 4)
 ----------------------------
 Three rules keep throughput scaling with device count instead of against
-it (BENCH_r05 measured the old path at 0.73x one device):
+it (the always-coupled path ran slower on eight shards than on one):
 
 - **Collective-lean launches**: staging classifies each batch — psum
   only when a global-namespace hit is present, pmin only when some
@@ -409,47 +409,46 @@ class TpuShardedStorage(_BigLimitMixin, CounterStorage):
             )
             counts = np.asarray(counts)
             slots = np.asarray(slots)
+            if not (counts > 0).any():
+                return []
             out: List[dict] = []
             g_counts: Dict[int, int] = {}
-            loc_sh: List[int] = []
-            loc_sl: List[int] = []
-            loc_count: List[int] = []
+            # Gather the drained coordinates — never the table — all
+            # n*k of them, filler included: a gather sized by the live
+            # count is a new program (compiled under this lock) for
+            # every count it has not seen.
+            sh = np.repeat(np.arange(self._n, dtype=np.int32), kk)
+            sl = slots.reshape(-1).astype(np.int32)
+            vals = np.asarray(self._state.values[sh, sl]).reshape(
+                self._n, kk)
+            exps = np.asarray(self._state.expiry_ms[sh, sl]).reshape(
+                self._n, kk)
             for s in range(self._n):
-                for j in range(counts.shape[1]):
+                for j in range(kk):
                     c = int(counts[s, j])
                     if c <= 0:
                         continue
                     slot = int(slots[s, j])
                     if slot < self._global_region:
                         g_counts[slot] = g_counts.get(slot, 0) + c
-                    else:
-                        loc_sh.append(s)
-                        loc_sl.append(slot)
-                        loc_count.append(c)
-            if loc_sl:
-                # Gather ONLY the drained coordinates — never the table.
-                sh = np.asarray(loc_sh, np.int32)
-                sl = np.asarray(loc_sl, np.int32)
-                vals = np.asarray(self._state.values[sh, sl])
-                exps = np.asarray(self._state.expiry_ms[sh, sl])
-                for i in range(sh.shape[0]):
-                    shard, slot = int(sh[i]), int(sl[i])
-                    record = {
-                        "slot": slot, "shard": shard,
-                        "count": loc_count[i],
-                    }
-                    entry = self._tables[shard].info.get(slot)
+                        continue
+                    record = {"slot": slot, "shard": s, "count": c}
+                    entry = self._tables[s].info.get(slot)
                     if entry is not None:
-                        ttl = max(int(exps[i]) - now_ms, 0)
-                        value = int(vals[i]) if ttl > 0 else 0
+                        ttl = max(int(exps[s, j]) - now_ms, 0)
+                        value = int(vals[s, j]) if ttl > 0 else 0
                         record.update(
                             hot_attribution(entry[1], value, ttl)
                         )
                     out.append(record)
             if g_counts:
+                # the whole (small, fixed-size) global region, for the
+                # same reason
                 gsl = np.asarray(sorted(g_counts), np.int32)
-                gvals = np.asarray(self._state.values[:, gsl])
-                gexps = np.asarray(self._state.expiry_ms[:, gsl])
+                region = self._global_region
+                gvals = np.asarray(self._state.values[:, :region])[:, gsl]
+                gexps = np.asarray(
+                    self._state.expiry_ms[:, :region])[:, gsl]
                 live = gexps > now_ms
                 value_sum = (gvals * live).sum(axis=0)
                 ttls = np.maximum(gexps.max(axis=0) - now_ms, 0)

@@ -631,13 +631,17 @@ class TpuStorage(_BigLimitMixin, CounterStorage):
             live = counts > 0
             if not live.any():
                 return []
-            slots = slots[live].astype(np.int32)
-            counts = counts[live]
+            # Gather all k slots, filler included, and filter on the
+            # host: the gather's shape is part of its program, and one
+            # sized by the live count compiled anew (under this lock)
+            # for every count it had not seen.
             values, ttls = K.read_slots(
-                self._state, slots, np.int32(now_ms)
+                self._state, slots.astype(np.int32), np.int32(now_ms)
             )
-            values = np.asarray(values)
-            ttls = np.asarray(ttls)
+            values = np.asarray(values)[live]
+            ttls = np.asarray(ttls)[live]
+            slots = slots[live]
+            counts = counts[live]
             out: List[dict] = []
             info = self._table.info
             for i, slot in enumerate(slots.tolist()):

@@ -399,8 +399,8 @@ def test_monitor_mode_counts_sheds_but_admits():
 
 
 class HangableStorage(TpuStorage):
-    """TpuStorage whose device->host collect path can be wedged, the
-    hung-device_sync failure mode of DEVICE_PROBES_r05.log."""
+    """TpuStorage whose device->host collect path can be wedged: the
+    hung-device_sync failure mode."""
 
     def __init__(self, *args, **kwargs):
         super().__init__(*args, **kwargs)

@@ -269,6 +269,26 @@ def test_mid_flight_limits_reload_honors_epoch():
     assert after["epoch"] > before["epoch"]
 
 
+def test_kernel_columns_do_not_alias_the_staging_buffers():
+    """A launch does not consume its host arguments before it returns
+    (the CPU backend aliases an aligned numpy buffer; an accelerator
+    may read it until the transfer completes), and the next begin
+    rewrites the staging buffers: the columns a launch takes must be
+    owned copies,
+    or a lagging launch decides the NEXT batch's rows. ``fresh`` is
+    never written and may stay a view."""
+    p, _ = _build(True)
+    lane = p._hot_lane
+    cols = lane.kernel_columns(64)
+    staging = (lane.slots, lane.deltas, lane.maxes, lane.windows,
+               lane.req, None, lane.bucket)
+    assert len(cols) == len(staging)
+    for col, buf in zip(cols, staging):
+        assert col.shape == (64,)
+        if buf is not None:
+            assert not np.shares_memory(col, buf)
+
+
 def test_stale_epoch_put_is_discarded():
     """The put-side half of the race: a plan derived under epoch E must
     not enter the mirror once the epoch moved past E (the derivation

@@ -249,7 +249,7 @@ class TestHttpApi:
         assert st == 429
         assert headers["X-RateLimit-Remaining"] == "0"
 
-    def test_check_report_split(self, http_server):
+    def test_check_and_report_split(self, http_server):
         port, _ = http_server
         body = {
             "namespace": "test_namespace",

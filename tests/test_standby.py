@@ -409,12 +409,10 @@ def test_standby_off_default_and_unarmed_pin():
 
     default = build_parser().parse_args(["limits.yaml", "memory"])
     assert default.standby == "off"
-    assert default.xla_cache_dir == ""
     on = build_parser().parse_args(
-        ["limits.yaml", "tpu", "--standby", "on",
-         "--xla-cache-dir", "/tmp/x"]
+        ["limits.yaml", "tpu", "--standby", "on"]
     )
-    assert on.standby == "on" and on.xla_cache_dir == "/tmp/x"
+    assert on.standby == "on"
 
     pytest.importorskip("grpc")
     from limitador_tpu import Limit, RateLimiter
@@ -515,20 +513,13 @@ def test_flight_recorder_has_join_lane():
     assert "join" in FLIGHT_LANES
 
 
-# -- the persistent XLA cache (--xla-cache-dir, slow) --------------------------
+# -- the persistent XLA cache (JAX_COMPILATION_CACHE_DIR, slow) ----------------
 
 _XLA_WARM_SNIPPET = """
 import os, sys, time
 import jax
-jax.config.update("jax_compilation_cache_dir", sys.argv[1])
-for knob, val in (
-    ("jax_persistent_cache_min_compile_time_secs", 0.0),
-    ("jax_persistent_cache_min_entry_size_bytes", 0),
-):
-    try:
-        jax.config.update(knob, val)
-    except Exception:
-        pass
+from limitador_tpu.device import enable_compile_cache
+assert enable_compile_cache() == os.environ["JAX_COMPILATION_CACHE_DIR"]
 from limitador_tpu.ops import kernel as K
 import jax.numpy as jnp
 import numpy as np
@@ -549,22 +540,23 @@ print(round(time.perf_counter() - t0, 4))
 
 
 @pytest.mark.slow
-def test_xla_cache_dir_persists_kernel_compiles(tmp_path):
-    """Satellite acceptance: with ``--xla-cache-dir`` a SECOND process
-    warming the same kernels hits the persistent cache — the compiled
-    programs are on disk after the first boot and no new cache entries
-    are written by the re-warm."""
+def test_compile_cache_persists_kernel_compiles(tmp_path):
+    """A SECOND process warming the same kernels hits the persistent
+    cache the environment placed — the compiled programs are on disk
+    after the first boot and no new cache entries are written by the
+    re-warm."""
     cache_dir = tmp_path / "xla"
     cache_dir.mkdir()
 
     def run():
         proc = subprocess.run(
-            [sys.executable, "-c", _XLA_WARM_SNIPPET, str(cache_dir)],
+            [sys.executable, "-c", _XLA_WARM_SNIPPET],
             capture_output=True, text=True, timeout=300,
             env={
                 "PYTHONPATH": str(REPO_ROOT),
                 "PATH": "/usr/bin:/bin:/usr/local/bin",
                 "JAX_PLATFORMS": "cpu",
+                "JAX_COMPILATION_CACHE_DIR": str(cache_dir),
                 "HOME": str(tmp_path),
             },
             cwd=str(REPO_ROOT),

@@ -187,6 +187,109 @@ def test_batch_single_slot_contention_admits_exactly_max():
     assert got[:100].all() and not got[100:].any()
 
 
+def _serial_oracle_mixed(batch, values, expiry, now_ms):
+    """``_serial_oracle`` over both lanes: a hit is (slot, delta, max,
+    window_or_interval, is_bucket); a bucket cell's expiry entry is its
+    TAT (storage/gcra.py: conforms iff spent + delta <= B)."""
+    admitted = []
+    for hits in batch:
+        ok = True
+        for slot, delta, maxv, win, is_bucket in hits:
+            if is_bucket:
+                base_rel = max(expiry.get(slot, 0) - now_ms, 0)
+                v = maxv - (((maxv - 1) * win - base_rel) // win + 1)
+            else:
+                v = 0 if now_ms >= expiry.get(slot, 0) else values.get(slot, 0)
+            if v + delta > maxv:
+                ok = False
+                break
+        if ok:
+            for slot, delta, _maxv, win, is_bucket in hits:
+                if is_bucket:
+                    expiry[slot] = max(expiry.get(slot, 0), now_ms) + delta * win
+                elif now_ms >= expiry.get(slot, 0):
+                    values[slot] = delta
+                    expiry[slot] = now_ms + win
+                else:
+                    values[slot] = values.get(slot, 0) + delta
+        admitted.append(ok)
+    return admitted
+
+
+def test_full_width_every_bucket_vs_serial_oracle():
+    """The serving shapes, all of them: a default deployment's table
+    (2^20 slots + the scratch row, an odd length) driven at every pow2
+    hit bucket the batcher can emit (8..8192) with both lanes live in
+    one batch — contended hot slots, multi-counter requests that couple
+    a window cell to a bucket cell, slots spread over the whole table —
+    decided exactly as the serial oracle does, state carried (donated)
+    from bucket to bucket. Then the off-path kernels once each at that
+    width: read, top-k drain, clear, epoch rebase. The same test passes
+    on one chip (JAX_PLATFORMS=tpu)."""
+    capacity = 1 << 20
+    rng = np.random.default_rng(21)
+    state = K.make_table(capacity)
+    values, expiry = {}, {}
+    now_ms = 10_000
+    hot = rng.integers(0, capacity // 2, 8) * 2  # even slots: windows
+    H = 8
+    while H <= 8192:
+        batch, nhits = [], 0
+        while nhits < H - 1:
+            roll = rng.random()
+            if roll < 0.3:      # contended hot window cell
+                slot = int(rng.choice(hot))
+            elif roll < 0.6:    # wide window cell (incl. the last row)
+                slot = int(rng.integers(0, capacity // 2)) * 2
+            else:               # bucket cell, 16 hot ones + wide
+                slot = int(rng.integers(0, 16 if roll < 0.8
+                                        else capacity // 2)) * 2 + 1
+            hits = [(slot, 1, 3, 60_000, False) if slot % 2 == 0
+                    else (slot, 1, 4, 250, True)]
+            if rng.random() < 0.25:  # couple to one cell of the other lane
+                other = int(rng.choice(hot)) if slot % 2 else 1
+                hits.append((other, 1, 3, 60_000, False) if other % 2 == 0
+                            else (other, 1, 4, 250, True))
+            batch.append(hits)
+            nhits += len(hits)
+        assert _bucket(nhits) == H
+        cols = [np.full(H, capacity, np.int32), np.zeros(H, np.int32),
+                np.full(H, np.iinfo(np.int32).max, np.int32),
+                np.zeros(H, np.int32), np.full(H, H - 1, np.int32)]
+        bucket = np.zeros(H, bool)
+        i = 0
+        for r, hits in enumerate(batch):
+            for slot, delta, maxv, win, is_bucket in hits:
+                for col, v in zip(cols, (slot, delta, maxv, win, r)):
+                    col[i] = v
+                bucket[i] = is_bucket
+                i += 1
+        state, result = K.check_and_update_batch(
+            state, *cols, np.zeros(H, bool), bucket, np.int32(now_ms))
+        got = np.asarray(result.admitted)[: len(batch)]
+        want = _serial_oracle_mixed(batch, values, expiry, now_ms)
+        assert list(got) == want, f"bucket {H}"
+        assert any(want) and (H < 256 or not all(want)), (
+            f"bucket {H}: the batch exercised one verdict only")
+        now_ms += 300  # a bucket token refills between batches
+        H *= 2
+
+    live = np.asarray(sorted(values), np.int32)[:4096]
+    v, _ttl = K.read_slots(state, live, np.int32(now_ms))
+    assert [int(x) for x in v] == [values[int(sl)] for sl in live]
+    new_hits, counts, slots = K.drain_top_hits(state.hits, 64)
+    assert int(counts[0]) > 0 and int(np.asarray(new_hits).sum()) == 0
+    assert set(int(sl) for sl in np.asarray(slots)[:4]) <= (
+        set(int(h) for h in hot) | set(range(1, 32, 2)))
+    state = K.CounterTableState(state.values, state.expiry_ms, new_hits)
+    state = K.clear_slots(state, live)
+    v, _ttl = K.read_slots(state, live, np.int32(now_ms))
+    assert not np.asarray(v).any()
+    before = np.asarray(state.expiry_ms)
+    state = K.rebase_epoch(state, np.int32(5_000))
+    assert (np.asarray(state.expiry_ms) == np.maximum(before - 5_000, 0)).all()
+
+
 def test_batch_multi_limit_coupling():
     """A request rejected by one counter must not consume from its other
     counters (all-or-nothing), freeing room for later requests."""

@@ -262,6 +262,24 @@ def test_storage_drain_top_ordering_and_k():
     assert [r["hits"] for r in top] == [40, 12]
 
 
+def test_storage_drain_compiles_no_program_per_live_count():
+    """The drain's gather runs under the storage lock every drain
+    period: its shape must not follow how many slots saw traffic, or
+    every count not seen before compiles a new program there (half a
+    second each on a TPU)."""
+    storage = TpuStorage(capacity=1 << 10)
+    limit = Limit("api", 10**6, 60, [], ["u"], name="fw")
+    programs = []
+    for n_live in (1, 3, 5, 2):
+        for user in range(n_live):
+            storage.check_many(
+                [_Request([Counter(limit, {"u": str(user)})], 1, False)]
+            )
+        assert len(storage.drain_hot_slots(8)) == n_live
+        programs.append(K.read_slots._cache_size())
+    assert len(set(programs)) == 1, programs
+
+
 def test_sharded_drain_attribution_including_globals():
     from limitador_tpu.parallel.mesh import make_mesh
     from limitador_tpu.tpu.sharded import TpuShardedStorage
